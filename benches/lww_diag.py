@@ -45,7 +45,7 @@ sys.path.insert(0, str(Path(__file__).resolve().parent.parent))
 import jax
 import jax.numpy as jnp
 
-from benches.bench_baseline import HBM_SPEC_TB_S, _timed
+from benches.bench_baseline import _timed, device_info, peak_tb_s
 
 R = 1 << 25
 SHAPE = (R // 128, 128)
@@ -142,11 +142,13 @@ def main():
     per = _timed(fn, 32, 256)
     floor = 3 * planes * R * 4  # read self + read peer + write, per plane
     eff = floor / per / 1e12
+    peak = peak_tb_s()
     print(json.dumps({
         "variant": args.variant,
+        "device": device_info(),
         "ms_per_step": round(per * 1e3, 3),
         "eff_tb_s": round(eff, 3),
-        "pct_hbm_spec": round(100 * eff / HBM_SPEC_TB_S, 1),
+        "pct_hbm_spec": None if peak is None else round(100 * eff / peak, 1),
         "merges_per_s": round(R / per, 1),
     }), flush=True)
 

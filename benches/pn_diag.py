@@ -165,8 +165,14 @@ def main():
             "merges_per_s": round(R / per, 1),
         }), flush=True)
         return
-    # driver: one subprocess per variant for a clean HBM each
+    # driver: one subprocess per variant for a clean HBM each — run one
+    # at a time, from a parent that never initializes a backend (a chip
+    # belongs to one process)
     import subprocess
+
+    from jax._src import xla_bridge
+
+    assert not xla_bridge.backends_are_initialized()
     for name in ("current", "split", "fused", "control"):
         proc = subprocess.run(
             [sys.executable, __file__, "--variant", name],

@@ -261,6 +261,30 @@ def run_daemon(args) -> int:
     return 0
 
 
+def check_platform(want: str, found: str) -> str:
+    """``found`` (the backend JAX initialized) when it satisfies the
+    ``--platform`` request ``want``; else SystemExit — a daemon asked for
+    a chip never carries on somewhere else."""
+    if want not in ("auto", found):
+        raise SystemExit(
+            f"--platform {want} requested but JAX initialized {found!r}")
+    return found
+
+
+def select_platform(want: str) -> str:
+    """Pin (or, for ``auto``, leave) JAX's backend and initialize it at
+    boot, so a missing device fails here and not at the first write."""
+    import jax
+
+    if want != "auto":
+        jax.config.update("jax_platforms", want)
+    try:
+        found = jax.devices()[0].platform
+    except RuntimeError as exc:
+        raise SystemExit(f"--platform {want}: no backend ({exc})") from exc
+    return check_platform(want, found)
+
+
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(
         prog="python -m crdt_tpu",
@@ -352,17 +376,17 @@ def main(argv=None) -> int:
                          "this many hash shards (0 = single-plane layout); "
                          "shard planes checkpoint/restore through the "
                          "same manifest machinery as the KV node")
-    ap.add_argument("--platform", choices=["cpu", "tpu", "ambient"],
-                    default="cpu",
-                    help="JAX backend for the host runtime (default cpu: "
-                         "a handful of replicas' merges are host-latency "
-                         "bound; the chip pays off at swarm scale — see "
-                         "bench.py/benches/)")
+    ap.add_argument("--platform", choices=["auto", "cpu", "tpu"],
+                    default="auto",
+                    help="JAX backend (default auto: the backend JAX "
+                         "finds — the chip on a chip machine; cpu for CI "
+                         "and the soaks; tpu fails at boot without one)")
     args = ap.parse_args(argv)
-    if args.platform != "ambient":
-        import jax
+    platform = select_platform(args.platform)
+    print(f"crdt_tpu: platform={platform}", file=sys.stderr, flush=True)
+    from crdt_tpu.utils import compile_cache
 
-        jax.config.update("jax_platforms", args.platform)
+    compile_cache.enable()
     return run_daemon(args) if args.daemon else run_demo(args)
 
 

@@ -1535,7 +1535,7 @@ class ReplicaNode:
         needed = size + fresh
         while needed > self.log.capacity:
             self._grow()
-        batch_cap = max(fresh, 1)
+        batch_cap = oplog.batch_capacity(fresh)
         # ONE device dispatch per ingest batch, however many peers' rows it
         # fuses (receive_many) — the counter the dispatch-count assertions
         # pin (crdt_merge_dispatches_total on /metrics).  The self log is
@@ -1548,7 +1548,7 @@ class ReplicaNode:
         # reflects EVERY set-union the node runs, not just ORSet joins
         union_engine.record_union_path("sort")
         self._count_lane_fold()
-        batch = oplog.from_ops(batch_cap, ops)
+        batch = oplog.from_host_ops(batch_cap, ops)
         timing = self.recorder.enabled
         t0 = time.perf_counter() if timing else 0.0
         with devtime.dispatch_annotation("merge", enabled=timing):

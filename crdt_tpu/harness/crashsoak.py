@@ -161,6 +161,9 @@ class Daemon:
         assert self.proc is None or self.proc.poll() is not None
         argv = [
             sys.executable, "-m", "crdt_tpu", "--daemon",
+            # several daemons run at once: pinned to the CPU, so none of
+            # them contends for (or waits on) a chip another one holds
+            "--platform", "cpu",
             "--rid", str(self.slot), "--port", str(self.port),
             "--peers", ",".join(self.peer_urls),
             "--checkpoint-dir", self.ckpt_dir,

@@ -253,6 +253,10 @@ class KeyspaceFrontDoor:
                 groups.setdefault(shard, []).append(
                     (ts, {qualify(tenant, k): v}, tenant))
         tickets = self._submit_groups(groups, tenant)  # ShedError whole
+        if self.ks.mesh_active:
+            # the page's fan-out lands in ONE fused mesh dispatch, not
+            # one inline drain per shard lane as the tickets would
+            self.flush_all_fused()
         with self._wm_lock:
             prev = self._page_watermark.get(page.origin)
             if prev is None or page.page_seq > prev:

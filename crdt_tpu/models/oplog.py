@@ -36,6 +36,7 @@ from typing import Mapping
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from flax import struct
 
 from crdt_tpu.ops import joins as _joins
@@ -80,9 +81,15 @@ class KVState:
 
 
 def empty(capacity: int) -> OpLog:
-    s = jnp.full((capacity,), SENTINEL, jnp.int32)
-    z = jnp.zeros((capacity,), jnp.int32)
-    return OpLog(ts=s, rid=s, seq=s, key=s, val=z, payload=z,
+    # one buffer per column: the served merge DONATES its log, and a
+    # donation of two leaves sharing a buffer is refused on the chip
+    def s():
+        return jnp.full((capacity,), SENTINEL, jnp.int32)
+
+    def z():
+        return jnp.zeros((capacity,), jnp.int32)
+
+    return OpLog(ts=s(), rid=s(), seq=s(), key=s(), val=z(), payload=z(),
                  is_num=jnp.zeros((capacity,), bool))
 
 
@@ -118,6 +125,41 @@ def from_ops(capacity: int, ops: Mapping[str, jax.Array]) -> OpLog:
                  val=out[4], payload=out[5], is_num=out[6])
 
 
+COLUMNS = ("ts", "rid", "seq", "key", "val", "payload", "is_num")
+
+
+def from_host_ops(capacity: int, ops: Mapping[str, np.ndarray]) -> OpLog:
+    """:func:`from_ops` for host (numpy) op columns, sorted on the host —
+    the served ingest path.  ``np.lexsort`` is stable, so the rows land
+    in exactly the order of from_ops's stable 4-key device sort, and the
+    merge that follows runs no sort on the device at all."""
+    m = len(ops["ts"])
+    assert m <= capacity, f"op batch {m} exceeds log capacity {capacity}"
+    order = np.lexsort((ops["key"], ops["seq"], ops["rid"], ops["ts"]))
+    out = {}
+    for name in COLUMNS:
+        if name == "is_num":
+            col = np.zeros(capacity, bool)
+        elif name in ("val", "payload"):
+            col = np.zeros(capacity, np.int32)
+        else:
+            col = np.full(capacity, SENTINEL, np.int32)
+        col[:m] = ops[name][order]
+        out[name] = col
+    return OpLog(**out)
+
+
+MIN_BATCH = 256
+
+
+def batch_capacity(n: int) -> int:
+    """Ingest batch capacity for ``n`` fresh rows: the next power of two,
+    at least :data:`MIN_BATCH`, so a stream of batches compiles a handful
+    of merge shapes, not one per distinct batch size (a merge's device
+    cost is dominated by the log's capacity, not by this padding)."""
+    return max(MIN_BATCH, 1 << max(n - 1, 0).bit_length())
+
+
 @partial(jax.jit, static_argnames="new_capacity")
 def grow(log: OpLog, new_capacity: int) -> OpLog:
     """Capacity migration: append tail padding (rows are sorted with
@@ -149,12 +191,14 @@ def merge(local: OpLog, remote: OpLog) -> OpLog:
 
 
 def _merge_checked(local: OpLog, remote: OpLog):
-    keys, vals, n_unique = su.sorted_union(
+    # both operands are sorted logs, so the union is a merge of two runs
+    # (su.merge_sorted_runs: bit-identical to sorted_union + keep_first,
+    # without its two 2C-row sorts or their TPU compile time)
+    keys, vals, n_unique = su.merge_sorted_runs(
         (local.ts, local.rid, local.seq, local.key),
         {"val": local.val, "payload": local.payload, "is_num": local.is_num},
         (remote.ts, remote.rid, remote.seq, remote.key),
         {"val": remote.val, "payload": remote.payload, "is_num": remote.is_num},
-        combine=su.keep_first,
         out_size=local.capacity,
     )
     return (
@@ -302,8 +346,6 @@ def materialize(kv: KVState, keys, values) -> dict:
     """Decode a KVState back to the reference's {key: string} map using the
     host interners (the inverse of the ingestion encoding).  Implements the
     KVState decode rule: verbatim raw string unless ≥2 numeric ops summed."""
-    import numpy as np
-
     present = np.asarray(kv.present)
     is_num = np.asarray(kv.is_num)
     num = np.asarray(kv.num)

@@ -36,9 +36,9 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 from flax import struct
+from jax import shard_map
 
 from crdt_tpu.models import oplog
-from crdt_tpu.parallel.compat import shard_map
 from crdt_tpu.ops import pallas_union
 from crdt_tpu.utils.constants import SENTINEL
 

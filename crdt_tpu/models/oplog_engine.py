@@ -151,7 +151,7 @@ class OpLogSwarm:
         pairwise union truncated (newest ops dropped) — same contract on
         both engines, so A/B comparisons are exact."""
         if self._col is not None:
-            col, nu = oc.converge_checked(
+            col, nu = _col_converge_checked(
                 self._col, self.alive, interpret=self.interpret
             )
             return self._wrap(col=col), nu
@@ -166,14 +166,11 @@ class OpLogSwarm:
         """One pull round: replica j joins peers[j]'s log, gated on both
         endpoints alive (the reference's 502-skip, main.go:235-239)."""
         if self._col is not None:
-            return self._wrap(col=oc.gossip_round(
+            return self._wrap(col=_col_gossip_round(
                 self._col, peers, self.alive, interpret=self.interpret
             ))
-        from crdt_tpu.parallel import swarm as swarm_mod
-
-        s = swarm_mod.Swarm(state=self._rows, alive=self.alive)
-        s = swarm_mod.gossip_round(s, peers, jax.vmap(oplog.merge))
-        return self._wrap(rows=s.state)
+        return self._wrap(rows=_generic_gossip_round(
+            self._rows, peers, self.alive))
 
     def set_alive(self, rid, alive_status) -> "OpLogSwarm":
         return self._wrap(
@@ -228,6 +225,24 @@ def plan(
                       interpret=interpret)
 
 
+# each swarm step is ONE compiled program: the tree reductions are
+# Python loops over halving shapes, which run eagerly would compile every
+# slice and merge of every level separately (minutes on a chip at 10K
+# lanes)
+_col_converge_checked = jax.jit(oc.converge_checked,
+                                static_argnames="interpret")
+_col_gossip_round = jax.jit(oc.gossip_round, static_argnames="interpret")
+
+
+@jax.jit
+def _generic_gossip_round(state: oplog.OpLog, peers, alive):
+    from crdt_tpu.parallel import swarm as swarm_mod
+
+    s = swarm_mod.Swarm(state=state, alive=alive)
+    return swarm_mod.gossip_round(s, peers, jax.vmap(oplog.merge)).state
+
+
+@jax.jit
 def _generic_converge_checked(state: oplog.OpLog, alive: jax.Array):
     """The row-major fallback of converge_checked: alive-masked log-depth
     tree reduction through the generic sorted_union, overflow tracked level

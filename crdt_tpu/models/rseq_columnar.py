@@ -37,9 +37,9 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 from flax import struct
+from jax import shard_map
 
 from crdt_tpu.models import rseq
-from crdt_tpu.parallel.compat import shard_map
 from crdt_tpu.ops import pallas_union
 from crdt_tpu.utils.constants import SENTINEL, SENTINEL_PY
 

@@ -60,9 +60,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from flax import struct
+from jax import shard_map
 
 from crdt_tpu.models import rseq, rseq_columnar as rc
-from crdt_tpu.parallel.compat import shard_map
 from crdt_tpu.models.oplog_engine import EngineFallback
 from crdt_tpu.ops import pallas_union
 from crdt_tpu.utils.constants import SENTINEL, SENTINEL_PY

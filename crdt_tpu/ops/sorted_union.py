@@ -108,6 +108,104 @@ def sorted_union(
     return tuple(keys), vals, n_unique
 
 
+def merge_sorted_runs(
+    keys_a: Sequence[jax.Array],
+    vals_a: Any,
+    keys_b: Sequence[jax.Array],
+    vals_b: Any,
+    out_size: int,
+) -> Tuple[Tuple[jax.Array, ...], Any, jax.Array]:
+    """:func:`sorted_union` with ``combine=keep_first`` for two inputs that
+    are ALREADY sorted runs — bit-identical output, no sort at all.
+
+    ``a`` must be sorted with unique keys and canonical padding (a log);
+    ``b`` must be sorted (adjacent duplicates are dropped, keep-first, as
+    sorted_union does).  Each ``b`` row finds its rank in ``a`` by a
+    branchless lexicographic binary search (log2(n_a) gathers per row);
+    the non-duplicate ``b`` rows are scattered into their output slots and
+    every other slot gathers its ``a`` row by a prefix count.  Work is
+    O(n_b log n_a + out_size), and the program is a few dozen straight-line
+    ops whatever the sizes — the two stable multi-operand sorts of
+    sorted_union cost O(n log n) and a TPU compile that grows ~4x per
+    doubling of n (hours at a 2**18-row shard log)."""
+    n_keys = len(keys_a)
+    assert n_keys == len(keys_b)
+    n_a = keys_a[0].shape[0]
+    n_b = keys_b[0].shape[0]
+
+    def lex_lt(xs, ys):
+        lt = xs[-1] < ys[-1]
+        for x, y in zip(xs[-2::-1], ys[-2::-1]):
+            lt = (x < y) | ((x == y) & lt)
+        return lt
+
+    def lex_eq(xs, ys):
+        eq = xs[0] == ys[0]
+        for x, y in zip(xs[1:], ys[1:]):
+            eq &= x == y
+        return eq
+
+    # rank[j] = #{a rows < b[j]} (lower bound; SENTINEL padding sorts last
+    # so it never counts for a real b row)
+    rank = jnp.zeros((n_b,), jnp.int32)
+    step = 1 << (n_a.bit_length() - 1) if n_a else 0
+    while step:
+        cand = rank + step
+        probe = [k[jnp.minimum(cand, n_a) - 1] for k in keys_a]
+        rank = jnp.where((cand <= n_a) & lex_lt(probe, keys_b), cand, rank)
+        step >>= 1
+    hit = [k[jnp.minimum(rank, n_a - 1)] for k in keys_a]
+    dup = (rank < n_a) & lex_eq(hit, keys_b)
+    prev = [jnp.concatenate([k[:1] - 1, k[:-1]]) for k in keys_b]
+    dup |= lex_eq(prev, keys_b)
+    take_b = (keys_b[0] != SENTINEL) & ~dup
+    n_unique = (jnp.sum(keys_a[0] != SENTINEL)
+                + jnp.sum(take_b)).astype(jnp.int32)
+
+    # output slot of each kept b row; the rest go out of range (dropped),
+    # each to its own index so the scatter's indices stay unique
+    tb = take_b.astype(jnp.int32)
+    pos = jnp.where(take_b, rank + _prefix_sum(tb) - tb,
+                    out_size + jnp.arange(n_b, dtype=jnp.int32))
+    from_b = jnp.zeros((out_size,), bool).at[pos].set(
+        True, mode="drop", unique_indices=True)
+    b_src = jnp.zeros((out_size,), jnp.int32).at[pos].set(
+        jnp.arange(n_b, dtype=jnp.int32), mode="drop", unique_indices=True)
+    fb = from_b.astype(jnp.int32)
+    slot = jnp.arange(out_size, dtype=jnp.int32)
+    a_src = jnp.clip(slot - (_prefix_sum(fb) - fb), 0, max(n_a - 1, 0))
+    live = slot < n_unique
+
+    def pick(xa, xb, pad):
+        return jnp.where(live, jnp.where(from_b, xb[b_src], xa[a_src]), pad)
+
+    keys = tuple(pick(ka, kb, SENTINEL) for ka, kb in zip(keys_a, keys_b))
+    vals = jax.tree.map(lambda xa, xb: pick(xa, xb, jnp.zeros((), xa.dtype)),
+                        vals_a, vals_b)
+    return keys, vals, n_unique
+
+
+_SCAN_BLOCK = 128
+
+
+def _prefix_sum(x: jax.Array) -> jax.Array:
+    """Inclusive prefix sum of a 1-D int32 count vector, as blocked int32
+    matmuls against an upper-triangular ones matrix — exact integer
+    arithmetic, the same numbers as ``jnp.cumsum``, whose TPU lowering
+    compiles in ~9 s at 2**19 rows (this: under 2 s)."""
+    n = x.shape[0]
+    blk = _SCAN_BLOCK
+    if n <= blk:
+        tri = jnp.triu(jnp.ones((n, n), jnp.int32))
+        return jnp.dot(x, tri, preferred_element_type=jnp.int32)
+    rows = jnp.pad(x, (0, -n % blk)).reshape(-1, blk)
+    tri = jnp.triu(jnp.ones((blk, blk), jnp.int32))
+    inner = jnp.dot(rows, tri, preferred_element_type=jnp.int32)
+    tot = inner[:, -1]
+    out = inner + (_prefix_sum(tot) - tot)[:, None]
+    return out.reshape(-1)[:n]
+
+
 def _bcast(mask: jax.Array, shape) -> jax.Array:
     """Broadcast a [n] mask against an [n, ...] value leaf."""
     return mask.reshape(mask.shape + (1,) * (len(shape) - 1))

@@ -25,9 +25,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax import shard_map
 
 from crdt_tpu.ops import joins
-from crdt_tpu.parallel.compat import shard_map
 from crdt_tpu.parallel import swarm as swarm_lib
 
 

@@ -12,37 +12,35 @@ regardless of S.
 
 Engine selection (what the compiled step is wrapped in):
 
-* ``pjit``      — modern jax: `jax.jit` with the lane axis pinned to the
-                  mesh via `with_sharding_constraint(NamedSharding(mesh,
+* ``pjit``      — `jax.jit` with the lane axis pinned to the mesh via
+                  `with_sharding_constraint(NamedSharding(mesh,
                   P(axis)))`; XLA partitions the vmapped fold across
-                  devices (GSPMD).  Preferred when >= 2 devices divide
-                  the lane count.
-* ``shard_map`` — the explicit per-device mapping through
-                  `parallel/compat.py` (absorbs the check_vma/check_rep
-                  version drift).  Fallback when pjit-style sharding
-                  args are unavailable.
+                  devices (GSPMD).  Chosen when >= 2 devices divide the
+                  lane count.
 * ``vmap``      — single-device fusion: still ONE dispatch for all S
-                  lanes, no cross-device partitioning.  What CPU CI
-                  without emulated host devices runs.
+                  lanes, no cross-device partitioning.  What one chip
+                  (or CPU CI without emulated host devices) runs.
 
-Bit-parity: each lane's fold is `lax.sort(batch, num_keys=4, stable)` +
-`oplog._merge_checked` — exactly the host path's `from_ops` +
-`merge_checked` (padding a batch with SENTINEL rows before the sort is
-identical to `from_ops`'s concat-then-sort, because SENTINEL keys sort
-last and the merge treats them as padding).  `tests/test_meshplane.py`
-pins per-shard state/vv bit-equality mesh-vs-host on randomized traces;
+Bit-parity: each lane's fold is `oplog._merge_checked` of its
+host-sorted, SENTINEL-padded batch (`oplog.from_host_ops`) — exactly the
+host path's `_merge_batch`.  `tests/test_meshplane.py` pins per-shard
+state/vv bit-equality mesh-vs-host on randomized traces;
 `benches/bench_keyspace.py --mesh` re-asserts it inside the timing loop.
 
 The plane operates on `PendingMerge` handles (api.node): each lane's
 host bookkeeping (accept, dedup, indexes, vv) already happened under
 that node's lock, which stays HELD across the fused step so commit
 rebinds the merged log race-free.  If the fused step itself fails, every
-lane falls back to its own inline host dispatch (`commit_inline`) — a
-lane is never left with host indexes ahead of its log.
+lane lands with its own inline host dispatch (`commit_inline`) — a lane
+is never left with host indexes ahead of its log — and the failure is
+made loud: ``meshplane_fallbacks`` ticks, a ``meshplane_fallback`` event
+carrying the error text lands in the node's events, and an
+`EngineFallback` warning is raised (an error under
+``warnings.simplefilter("error", EngineFallback)``, as chip_smoke.py runs).
 """
 from __future__ import annotations
 
-import inspect
+import warnings
 from typing import Any, Callable, Dict, List, Optional, Tuple
 
 import jax
@@ -51,29 +49,20 @@ import numpy as np
 
 from crdt_tpu.models import oplog
 from crdt_tpu.ops import union_engine
-from crdt_tpu.parallel.compat import HAS_SHARD_MAP, shard_map
 from crdt_tpu.parallel.mesh import make_mesh
-from crdt_tpu.utils.constants import SENTINEL
 from crdt_tpu.utils.metrics import Metrics
 
 MESH_MODES = ("auto", "on", "off")
 
-_BATCH_COLS = ("ts", "rid", "seq", "key", "val", "payload", "is_num")
-
-
-def _has_pjit() -> bool:
-    """Does this jax expose jit-level sharding args (the GSPMD path)?"""
-    try:
-        from jax.sharding import NamedSharding  # noqa: F401
-    except ImportError:
-        return False
-    return "in_shardings" in inspect.signature(jax.jit).parameters
+# a zero-fresh lane's ingest batch: pure padding (an identity fold)
+_NO_OPS = {name: np.zeros(0, bool if name == "is_num" else np.int32)
+           for name in oplog.COLUMNS}
 
 
 def _mesh_divisor(n_lanes: int, n_devices: int) -> int:
     """Largest device count d <= min(n_lanes, n_devices) with d | n_lanes
-    (both pjit sharding constraints and shard_map need the lane axis to
-    split evenly across the mesh)."""
+    (the pjit sharding constraint needs the lane axis to split evenly
+    across the mesh)."""
     for d in range(min(n_lanes, n_devices), 0, -1):
         if n_lanes % d == 0:
             return d
@@ -94,22 +83,14 @@ def select_engine(n_lanes: int, mode: str = "auto") -> Optional[str]:
     n_dev = len(jax.devices())
     if mode == "auto" and (n_dev < 2 or n_lanes < 2):
         return None
-    if _mesh_divisor(n_lanes, n_dev) >= 2:
-        if _has_pjit():
-            return "pjit"
-        if HAS_SHARD_MAP:
-            return "shard_map"
-    return "vmap"
+    return "pjit" if _mesh_divisor(n_lanes, n_dev) >= 2 else "vmap"
 
 
 def _lane_fold(log: oplog.OpLog, batch_cols: Tuple[jax.Array, ...]):
-    """One lane: canonical-sort the padded ingest batch (== from_ops) and
-    run the checked sorted-union merge.  Traced under vmap — the whole
-    mesh step is this, S times, in one program."""
-    out = jax.lax.sort(list(batch_cols), num_keys=4, is_stable=True)
-    batch = oplog.OpLog(ts=out[0], rid=out[1], seq=out[2], key=out[3],
-                        val=out[4], payload=out[5], is_num=out[6])
-    return oplog._merge_checked(log, batch)
+    """One lane: the checked merge of its host-sorted, padded ingest batch
+    (== the host path's from_host_ops + merge_checked).  Traced under
+    vmap — the whole mesh step is this, S times, in one program."""
+    return oplog._merge_checked(log, oplog.OpLog(*batch_cols))
 
 
 class MeshPlane:
@@ -135,14 +116,23 @@ class MeshPlane:
         self.axis = axis
         self.metrics = metrics if metrics is not None else Metrics()
         # the engine override pins a specific engine (tests exercise the
-        # shard_map fallback + single-device vmap paths explicitly)
+        # single-device vmap path explicitly on a multi-device host)
         self.engine = engine if engine is not None \
             else select_engine(n_lanes, mode)
+        if self.engine not in ("pjit", "vmap"):
+            raise ValueError(f"unknown mesh engine {self.engine!r}")
         self.mesh = None
+        self.sharding = None
         self.n_devices = 1
-        if self.engine in ("pjit", "shard_map"):
+        if self.engine == "pjit":
+            from jax.sharding import NamedSharding, PartitionSpec as P
+
             self.n_devices = _mesh_divisor(n_lanes, len(jax.devices()))
             self.mesh = make_mesh(self.n_devices, axis=axis)
+            self.sharding = NamedSharding(self.mesh, P(axis))
+        # devices the last step's stacked lanes were placed on (the
+        # multi-chip check reads it: one lane per chip, not all on 0)
+        self.last_devices: Tuple[int, ...] = ()
         self._steps: Dict[Tuple[int, int], Callable] = {}
 
     # ---- compiled step construction ----
@@ -151,24 +141,8 @@ class MeshPlane:
         n = self.n_lanes
         vfold = jax.vmap(_lane_fold)
 
-        if self.engine == "shard_map":
-            from jax.sharding import PartitionSpec as P
-
-            spec = P(self.axis)
-            sharded_fold = shard_map(
-                vfold, mesh=self.mesh,
-                in_specs=(spec, tuple(spec for _ in _BATCH_COLS)),
-                out_specs=(spec, spec),
-                check_vma=False,  # compat shim translates for 0.4.x
-            )
-
-            def run(logs, cols):
-                return sharded_fold(logs, cols)
-
-        elif self.engine == "pjit":
-            from jax.sharding import NamedSharding, PartitionSpec as P
-
-            sharding = NamedSharding(self.mesh, P(self.axis))
+        if self.engine == "pjit":
+            sharding = self.sharding
 
             def run(logs, cols):
                 logs = jax.tree.map(
@@ -241,28 +215,43 @@ class MeshPlane:
                     p.node.log = oplog.grow(p.node.log, cap)
                     p.node.metrics.inc("log_grow")
 
-            batch_cap = 1
-            while batch_cap < max(p.fresh for p in pendings):
-                batch_cap *= 2
+            batch_cap = oplog.batch_capacity(max(p.fresh for p in pendings))
 
             logs = jax.tree.map(
                 lambda *xs: jnp.stack(xs), *[p.node.log for p in pendings])
-            cols = tuple(
-                jnp.stack([_pad_col(p.ops, name, p.fresh, batch_cap)
-                           for p in pendings])
-                for name in _BATCH_COLS)
+            batches = [oplog.from_host_ops(batch_cap, p.ops or _NO_OPS)
+                       for p in pendings]
+            cols = tuple(np.stack([getattr(b, name) for b in batches])
+                         for name in oplog.COLUMNS)
             digs = np.stack([_pad_dig(p.dig, batch_cap) for p in pendings])
+            if self.sharding is not None:
+                # place each lane on its own device before the step, so
+                # the program's inputs are already split over the mesh
+                logs, cols = jax.device_put((logs, cols), self.sharding)
+            self.last_devices = tuple(sorted(
+                d.id for d in logs.ts.sharding.device_set))
 
             step = self._step_for(cap, batch_cap)
             with self.metrics.timer("merge"):
                 lanes, n_unique, dig_sum = step(logs, cols, digs)
                 # ONE host sync for all lanes' counts AND digest sums
                 n_host, dig_host = jax.device_get((n_unique, dig_sum))
-        except Exception:
+        except Exception as exc:
             # engine failure: land every lane with its own inline host
-            # dispatch so no lane is left with indexes ahead of its log
+            # dispatch so no lane is left with indexes ahead of its log,
+            # then make the failure loud — a fused step that fails on a
+            # chip must never look like one that ran
             self.metrics.inc("meshplane_fallbacks")
-            return land_all_inline(pendings)
+            total = land_all_inline(pendings)
+            error = f"{type(exc).__name__}: {exc}"
+            pendings[0].node.events.emit(
+                "meshplane_fallback", engine=self.engine, error=error)
+            from crdt_tpu.models.oplog_engine import EngineFallback
+
+            warnings.warn(
+                f"mesh plane {self.engine} step failed, lanes landed "
+                f"inline: {error}", EngineFallback, stacklevel=2)
+            return total
         # one fused device dispatch for ALL lanes — the counter the
         # one-dispatch-per-step assertions pin; per-lane attribution comes
         # from each node's _count_lane_fold (merge_dispatches{shard=i})
@@ -306,23 +295,6 @@ def land_all_inline(pendings: List[Any]) -> int:
     if first_exc is not None:
         raise first_exc
     return total
-
-
-def _pad_col(
-    ops: Optional[Dict[str, np.ndarray]], name: str, fresh: int, cap: int
-) -> np.ndarray:
-    """One lane's batch column padded to ``cap`` with from_ops's padding
-    encoding (SENTINEL lex keys, zero values) — pad-then-sort inside the
-    step is bit-identical to from_ops's concat-then-sort."""
-    if name == "is_num":
-        out = np.zeros(cap, bool)
-    elif name in ("val", "payload"):
-        out = np.zeros(cap, np.int32)
-    else:
-        out = np.full(cap, SENTINEL, np.int32)
-    if fresh:
-        out[:fresh] = ops[name]
-    return out
 
 
 def _pad_dig(dig: Optional[np.ndarray], cap: int) -> np.ndarray:

@@ -37,8 +37,6 @@ import jax
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from crdt_tpu.parallel.compat import distributed_is_initialized
-
 
 def init_from_env(
     coordinator_address: Optional[str] = None,
@@ -63,7 +61,7 @@ def init_from_env(
     bootstrap raises: silently proceeding single-host would let every host
     converge its own partition believing it is the global swarm.
     """
-    if distributed_is_initialized():
+    if jax.distributed.is_initialized():
         return True
     if autodetect is None:
         autodetect = os.environ.get("CRDT_TPU_MULTIHOST") == "1"

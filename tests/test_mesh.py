@@ -6,10 +6,10 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax import shard_map
 
 from crdt_tpu.models import gcounter, oplog
 from crdt_tpu.parallel import mesh as mesh_lib
-from crdt_tpu.parallel.compat import shard_map
 from crdt_tpu.parallel import swarm
 from tests import helpers
 from tests.helpers import tree_equal

@@ -7,8 +7,8 @@ would have produced.  These tests pin both halves:
 
 * randomized multi-tenant traces driven through a mesh keyspace and a
   host-path twin, compared per shard down to the raw OpLog columns —
-  for every engine (the auto-selected one, the shard_map compat-shim
-  fallback, and single-device vmap fusion);
+  for every engine (the auto-selected one and single-device vmap
+  fusion);
 * exactly ONE label-free `merge_dispatches` tick per converge (vs S on
   the host path), with per-shard attribution surviving as
   `merge_dispatches{shard=i}` labels — asserted on a rendered AND a
@@ -16,16 +16,19 @@ would have produced.  These tests pin both halves:
 * corrupt-shard isolation: a payload that fails structural validation
   quarantines ITS lane while the siblings still fold in the same step;
 * engine failure lands every lane via its own inline host dispatch
-  (commit_inline) — bits still right, `meshplane_fallbacks` ticks.
+  (commit_inline) — bits still right — and is LOUD: `meshplane_fallbacks`
+  ticks, a `meshplane_fallback` event carries the error text, and an
+  `EngineFallback` warning is raised.
 
 conftest.py pins JAX_PLATFORMS=cpu with 8 emulated host devices, so
-the pjit/shard_map engines get a real multi-device mesh under CI.
+the pjit engine gets a real multi-device mesh under CI.
 """
 from __future__ import annotations
 
 import json
 import random
 import re
+import warnings
 
 import jax
 import numpy as np
@@ -34,6 +37,7 @@ import pytest
 from crdt_tpu.api.node import ReplicaNode
 from crdt_tpu.keyspace import ShardedKeyspace, qualify
 from crdt_tpu.models import oplog
+from crdt_tpu.models.oplog_engine import EngineFallback
 from crdt_tpu.parallel.meshplane import (MESH_MODES, MeshPlane,
                                          _mesh_divisor, select_engine)
 from crdt_tpu.utils.clock import ManualClock
@@ -144,7 +148,7 @@ def test_config_knob_validated():
 
 # ---- bit-parity: mesh vs host twin, every engine ----
 
-@pytest.mark.parametrize("engine", [None, "shard_map", "vmap"])
+@pytest.mark.parametrize("engine", [None, "vmap"])
 def test_mesh_parity_randomized_multitenant(engine):
     """Randomized multi-tenant trace: after every fused converge, each
     mesh shard is bit-identical (state, vv, all 7 raw OpLog columns) to
@@ -277,7 +281,8 @@ def test_step_failure_falls_back_to_inline_commits():
     for i, p in enumerate(payloads):
         if p is not None:
             host.receive(i, p)
-    results = mesh.receive_all(payloads)
+    with pytest.warns(EngineFallback, match="injected engine failure"):
+        results = mesh.receive_all(payloads)
     assert all(isinstance(r, int) for r in results)
     _assert_shards_bit_equal(host, mesh)
     counts = mesh.shards[0].metrics._counts
@@ -285,6 +290,38 @@ def test_step_failure_falls_back_to_inline_commits():
     # fallback pays the per-lane dispatches (the host path's cost)
     assert counts["merge_dispatches"] == sum(
         1 for p in payloads if p is not None)
+
+
+def test_step_failure_is_loud_and_keeps_lanes_consistent():
+    """An injected fused-step failure must surface — the warning (an
+    error under simplefilter, as chip_smoke.py runs), the event with the
+    error text, the counter — and still leave every lane's host indexes
+    exactly level with its device log: no lane's vv runs ahead."""
+    _, mesh, clock = _twin_keyspaces()
+    plane = mesh._plane()
+
+    def boom(capacity, batch_cap):
+        raise RuntimeError("injected chip failure")
+
+    plane._step_for = boom
+    writers = _writers(mesh, clock)
+    payloads = _random_round(random.Random(9), mesh, writers, clock)
+    with warnings.catch_warnings():
+        warnings.simplefilter("error", EngineFallback)
+        with pytest.raises(EngineFallback, match="injected chip failure"):
+            mesh.receive_all(payloads)
+    assert mesh.shards[0].metrics._counts["meshplane_fallbacks"] == 1
+    events = mesh.shards[0].events.find(event="meshplane_fallback")
+    assert len(events) == 1
+    assert "RuntimeError: injected chip failure" in events[0]["error"]
+    for i, shard in enumerate(mesh.shards):
+        n_writers = shard._n_writers()
+        dev_vv = np.asarray(oplog.version_vector(shard.log, n_writers))
+        host_vv = shard.version_vector()
+        assert {r: int(v) for r, v in enumerate(dev_vv) if v >= 0} \
+            == host_vv, f"shard {i}: host indexes ahead of the log"
+        assert shard._log_rows == int(oplog.size(shard.log))
+    _assert_no_lock_leak(mesh)
 
 
 def test_lane_count_mismatch_aborts_cleanly():
