@@ -277,17 +277,16 @@ def store_digests(host):
 
 def warm_shapes(cap, max_batch, key_tables, counter, plane):
     """Compile every shape the served phase will dispatch before it is
-    counted: the donated ingest merge and its cost analysis, the mesh
-    host's own vmap step and its lane stacking, rebuild, version vector
-    and size at the shard capacity ``cap``, for every power-of-two batch
-    (from oplog.MIN_BATCH) and key-table size the run can reach."""
+    counted: the donated ingest merge, the mesh host's own vmap step and
+    its lane stacking, rebuild, version vector and size at the shard
+    capacity ``cap``, for every power-of-two batch (from oplog.MIN_BATCH)
+    and key-table size the run can reach."""
     import jax
     import jax.numpy as jnp
     import numpy as np
 
     from crdt_tpu.models import oplog
-    from crdt_tpu.obs import devtime
-    
+
     mark = counter.mark()
     t0 = time.perf_counter()
     no_ops = {c: np.zeros(0, bool if c == "is_num" else np.int32)
@@ -297,9 +296,8 @@ def warm_shapes(cap, max_batch, key_tables, counter, plane):
     b = oplog.MIN_BATCH
     while b <= max_batch:
         batch = oplog.from_host_ops(b, no_ops)
-        merged, n = oplog.merge_checked_donating(oplog.empty(cap), batch)
+        _, n = oplog.merge_checked_donating(oplog.empty(cap), batch)
         int(n)
-        devtime._cost_for(oplog.merge_checked_donating, (merged, batch))
         cols = tuple(np.stack([getattr(batch, c)] * N_SHARDS)
                      for c in oplog.COLUMNS)
         digs = np.zeros((N_SHARDS, b, 4), np.uint32)

@@ -166,7 +166,7 @@ from crdt_tpu.consistency.stability import STABILITY_HEADER, encode_summary
 from crdt_tpu.ingest import PageFormatError, ShedError
 from crdt_tpu.keyspace import TENANT_HEADER
 from crdt_tpu.obs import health
-from crdt_tpu.obs.trace import TRACE_HEADER, span
+from crdt_tpu.obs.trace import TRACE_HEADER, region, span
 
 PROM_CTYPE = "text/plain; version=0.0.4; charset=utf-8"
 
@@ -174,6 +174,18 @@ PROM_CTYPE = "text/plain; version=0.0.4; charset=utf-8"
 # page 409s when the writer's epoch is stale (see keyspace/reshard.py);
 # an un-stamped page routes by the live shard map, back-compatible
 KS_EPOCH_HEADER = "X-CRDT-KS-Epoch"
+
+# (method, path) -> the <route> of a handler's crdt.http.<route> span; a
+# fixed map, so a span name never carries a request's own path
+_ROUTES = {
+    ("POST", "/data"): "post_data",
+    ("POST", "/ingest/page"): "post_ingest_page",
+    ("GET", "/data"): "get_data",
+    ("GET", "/vv"): "get_vv",
+    ("GET", "/gossip"): "get_gossip",
+    ("GET", "/ks/gossip"): "get_ks_gossip",
+    ("GET", "/ks/data"): "get_ks_data",
+}
 
 
 def _make_handler(cluster: LocalCluster, idx: int, admin=None):
@@ -327,6 +339,22 @@ def _make_handler(cluster: LocalCluster, idx: int, admin=None):
                 return "bad"  # unparseable JSON / non-dict / non-int fields
 
         def do_GET(self):
+            with self._route_span("GET"):
+                self._get()
+
+        def do_POST(self):
+            with self._route_span("POST"):
+                self._post()
+
+        def _route_span(self, method: str):
+            """The ``crdt.http.<route>`` span over one whole handler;
+            ``#trace=<id>`` names the gossip round of a traced request."""
+            route = _ROUTES.get((method, self.path.partition("?")[0]),
+                                "other")
+            return region(f"crdt.http.{route}",
+                          self.headers.get(TRACE_HEADER))
+
+        def _get(self):
             url = urlparse(self.path)
             parts = [p for p in url.path.split("/") if p]
             if parts and parts[0] == "set" and self.set_node is not None:
@@ -722,7 +750,7 @@ def _make_handler(cluster: LocalCluster, idx: int, admin=None):
             else:
                 self._send(404, "not found")
 
-        def do_POST(self):
+        def _post(self):
             path = urlparse(self.path).path
             if path == "/ingest/page":
                 front = self.ingest

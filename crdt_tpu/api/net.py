@@ -47,7 +47,8 @@ from crdt_tpu.consistency.stability import (
 )
 from crdt_tpu.obs.audit import AuditWatchdog
 from crdt_tpu.obs.events import EventLog
-from crdt_tpu.obs.trace import TRACE_HEADER, mint_trace_id, span
+from crdt_tpu.obs.trace import (TRACE_HEADER, install_gc_spans,
+                                mint_trace_id, region, span)
 from crdt_tpu.utils.config import ClusterConfig
 from crdt_tpu.utils.metrics import Metrics
 
@@ -977,6 +978,7 @@ class NetworkAgent:
         self._composite_pull_fused(responding)
         return merged
 
+    @region("crdt.pull.set")
     def set_pull(self, peer: RemotePeer) -> bool:
         """One set-lattice pull from ``peer`` (no-op without a set node).
         Always delta-requested: the sender itself decides when a full
@@ -1014,6 +1016,7 @@ class NetworkAgent:
             )
             return 0
 
+    @region("crdt.pull.seq")
     def seq_pull(self, peer: RemotePeer) -> bool:
         """One sequence-lattice pull from ``peer`` (no-op without a seq
         node) — the seq sibling of set_pull, same delta-request and
@@ -1377,6 +1380,7 @@ class NetworkAgent:
         self.metrics.inc("seq_collections_scheduled")
         return floor
 
+    @region("crdt.pull.map")
     def map_pull(self, peer: RemotePeer) -> bool:
         """One map-lattice pull from ``peer`` (no-op without a map node)
         — the map sibling of set_pull; epoch-carrying deltas are always
@@ -1395,6 +1399,7 @@ class NetworkAgent:
         self.metrics.inc("map_gossip_rounds" if fresh else "map_gossip_noop")
         return fresh > 0
 
+    @region("crdt.pull.composite")
     def composite_pull(self, peer: RemotePeer) -> bool:
         """One composite-lattice pull from ``peer`` (no-op without a
         composite node) — the algebra sibling of map_pull, minus the vv:
@@ -1424,6 +1429,7 @@ class NetworkAgent:
             )
         return fresh > 0
 
+    @region("crdt.pull.composite")
     def _composite_pull_fused(self, peers: List[RemotePeer]) -> bool:
         """The composite leg of a k-way fused round: fetch every responding
         peer's state concurrently, decode each (per-peer quarantine), then
@@ -1513,36 +1519,42 @@ class NetworkAgent:
         rounds = 0
         while not self._stop.wait(period):
             try:
-                self.gossip_once()
                 rounds += 1
-                every = self.config.compact_every  # re-read: live reconfig
-                if self.coordinator and every and rounds % every == 0:
-                    self.compact_once()
-                # set GC runs on its OWN cadence: KV compaction may be
-                # forbidden (go-compat fleets) while set tables still need
-                # their tombstones reclaimed
-                sce = self.config.set_collect_every
-                if self.coordinator and sce and rounds % sce == 0:
-                    self.set_collect_once()
-                qce = self.config.seq_collect_every
-                if self.coordinator and qce and rounds % qce == 0:
-                    self.seq_collect_once()
-                mre = self.config.map_reset_every
-                if self.coordinator and mre and rounds % mre == 0:
-                    self.map_reset_once()
-                sge = self.config.stability_gc_every
-                if self.coordinator and sge and rounds % sge == 0:
-                    self.stability_gc_once()
-                # watchdog evaluators tick on EVERY node (divergence and
-                # stall detection must not die with the coordinator)
-                aee = self.config.audit_eval_every
-                if aee and rounds % aee == 0:
-                    self.watchdog.evaluate()
+                self._round(rounds)
             except Exception as e:  # noqa: BLE001 — surfaced via stop()
                 self.metrics.inc("net_gossip_loop_errors")
                 with self._err_lock:
                     self.errors.append(e)
                 raise
+
+    @region("crdt.agent.round")
+    def _round(self, rounds: int) -> None:
+        """Round ``rounds``'s work, not the period wait: the pulls plus
+        the cadenced compaction, GC and watchdog ticks."""
+        self.gossip_once()
+        every = self.config.compact_every  # re-read: live reconfig
+        if self.coordinator and every and rounds % every == 0:
+            self.compact_once()
+        # set GC runs on its OWN cadence: KV compaction may be
+        # forbidden (go-compat fleets) while set tables still need
+        # their tombstones reclaimed
+        sce = self.config.set_collect_every
+        if self.coordinator and sce and rounds % sce == 0:
+            self.set_collect_once()
+        qce = self.config.seq_collect_every
+        if self.coordinator and qce and rounds % qce == 0:
+            self.seq_collect_once()
+        mre = self.config.map_reset_every
+        if self.coordinator and mre and rounds % mre == 0:
+            self.map_reset_once()
+        sge = self.config.stability_gc_every
+        if self.coordinator and sge and rounds % sge == 0:
+            self.stability_gc_once()
+        # watchdog evaluators tick on EVERY node (divergence and
+        # stall detection must not die with the coordinator)
+        aee = self.config.audit_eval_every
+        if aee and rounds % aee == 0:
+            self.watchdog.evaluate()
 
 
 class NodeHost:
@@ -1593,6 +1605,9 @@ class NodeHost:
                 "peers: a full pull would receive the lossy bare-ms dump "
                 "(rid-less foreign ops) meant for Go peers only"
             )
+        # a collector pause on a replica's heap stalls every thread of the
+        # process: each gen-1/2 collection is a crdt.py_gc span
+        install_gc_spans()
         # event_log: JSONL file sink path — each gossip round / barrier /
         # fault transition appends one line (the daemon's black box; the
         # crash soak points this at the checkpoint dir)

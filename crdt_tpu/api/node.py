@@ -20,8 +20,6 @@ is the shared-interner fast path.
 from __future__ import annotations
 
 import json
-import threading
-import time
 from typing import Any, Dict, List, Optional, Tuple
 
 import numpy as np
@@ -31,7 +29,7 @@ from crdt_tpu.obs import devtime, health
 from crdt_tpu.ops import union_engine
 from crdt_tpu.obs.events import EventLog
 from crdt_tpu.obs.provenance import FlightRecorder
-from crdt_tpu.obs.trace import current_trace, span
+from crdt_tpu.obs.trace import SpanLock, current_trace, region, span
 from crdt_tpu.utils.clock import HostClock, SeqGen
 from crdt_tpu.utils.intern import Interner, encode_value
 from crdt_tpu.utils.metrics import Metrics
@@ -451,7 +449,9 @@ class ReplicaNode:
         self._wire_pending: List[Tuple[int, int, int, list, list]] = []
         self.alive = True
         self._seq = SeqGen()
-        self._lock = threading.Lock()
+        # contended acquisitions are crdt.wait.node_lock spans: a write
+        # queued behind a gossip receive or serve shows as that wait
+        self._lock = SpanLock("crdt.wait.node_lock")
         # host copy of raw commands per op, for gossip serving:
         # (ts, rid, seq) -> {key: value}
         self._commands: Dict[Tuple[int, int, int], Dict[str, str]] = {}
@@ -1358,7 +1358,8 @@ class ReplicaNode:
                 self._foreign.append((ident, stored))
             accepted.append((ts, rid, seq, stored))
         if accepted and self.digest is not None and self.digest.enabled:
-            self.digest.observe_rows(accepted, self.clock.epoch_ms)
+            with region("crdt.audit.observe"):
+                self.digest.observe_rows(accepted, self.clock.epoch_ms)
         return accepted
 
     def _pack_accepted_locked(
@@ -1496,10 +1497,11 @@ class ReplicaNode:
             seq += 1
         self._vv[rid] = max(self._vv.get(rid, -1), seq - 1)
         if self.digest is not None and self.digest.enabled:
-            self.digest.observe_rows(
-                [(t, rid, seq0 + i, c) for i, (c, t) in
-                 enumerate(zip(cmds, tss))],
-                epoch)
+            with region("crdt.audit.observe"):
+                self.digest.observe_rows(
+                    [(t, rid, seq0 + i, c) for i, (c, t) in
+                     enumerate(zip(cmds, tss))],
+                    epoch)
         fresh = len(c_eidx)
         if not fresh:
             return None, 0
@@ -1549,20 +1551,9 @@ class ReplicaNode:
         union_engine.record_union_path("sort")
         self._count_lane_fold()
         batch = oplog.from_host_ops(batch_cap, ops)
-        timing = self.recorder.enabled
-        t0 = time.perf_counter() if timing else 0.0
-        with devtime.dispatch_annotation("merge", enabled=timing):
+        with devtime.dispatch_annotation("merge"):
             merged, n_unique = oplog.merge_checked_donating(self.log, batch)
-        # int(n_unique) is a host sync: by the time the assert runs the
-        # dispatch has completed, so t1 - t0 is true device+dispatch wall
-        # time — the denominator of the roofline ratio (obs/devtime)
         assert int(n_unique) <= self.log.capacity
-        if timing:
-            devtime.observe_join(
-                self.metrics.registry, str(self.rid),
-                oplog.merge_checked_donating, (self.log, batch),
-                time.perf_counter() - t0,
-            )
         self.log = merged
         self._log_rows = int(n_unique)  # already synced by the assert
         self.metrics.inc("ops_ingested", fresh)

@@ -33,6 +33,7 @@ from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
 from crdt_tpu.ingest import wire
 from crdt_tpu.ingest.shed import ShedError, ShedPolicy
+from crdt_tpu.obs.trace import region
 from crdt_tpu.utils.metrics import Metrics
 
 
@@ -59,6 +60,7 @@ class Ticket:
     def done(self) -> bool:
         return self._event.is_set()
 
+    @region("crdt.wait.admit")
     def wait(self, timeout: Optional[float] = None) -> List[Any]:
         """Block until drained; the cooperative deadline flush keeps a
         lone submitter from waiting forever on an idle queue."""
@@ -275,7 +277,8 @@ class AdmissionQueue:
         if claim is None:
             return 0
         try:
-            results = self.flush_fn(claim.flat)
+            with region("crdt.drain"):
+                results = self.flush_fn(claim.flat)
         except BaseException as exc:
             return claim.fail(exc)
         return claim.resolve(results)

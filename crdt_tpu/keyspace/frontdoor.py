@@ -32,6 +32,7 @@ from crdt_tpu.ingest.admission import AdmissionQueue
 from crdt_tpu.ingest.shed import ShedPolicy
 from crdt_tpu.keyspace.routing import validate_tenant
 from crdt_tpu.keyspace.shards import ShardedKeyspace, qualify
+from crdt_tpu.obs.trace import region
 
 # lane label a tenant-quota shed is accounted under (the lane itself had
 # room — the tenant's slice did the shedding)
@@ -309,6 +310,7 @@ class KeyspaceFrontDoor:
             return self.flush_all_fused()
         return sum(lane.flush() for lane in self.lanes)
 
+    @region("crdt.drain")
     def flush_all_fused(self) -> int:
         """Drain EVERY shard lane through ONE device-mesh step.
 

@@ -311,10 +311,10 @@ def converge_checked(
     state.  Returns (ColumnarOpLog, max_n_unique): max_n_unique > capacity
     means some pairwise union overflowed (newest ops dropped) — the same
     silent-truncation contract as the generic path, made checkable."""
-    from crdt_tpu.utils.tracing import trace_region
+    from crdt_tpu.obs.trace import region
 
     lanes = col.lanes
-    with trace_region("oplog_columnar.converge"):
+    with region("crdt.oplog_columnar.converge"):
         work, max_nu = lub_lane(col, alive, interpret=interpret)
         top = jax.tree.map(
             lambda x: jnp.broadcast_to(x[:, :1], (col.capacity, lanes)), work

@@ -322,9 +322,9 @@ def converge_checked(
     tables — swarm.converge for the sequence CRDT on the fused kernel.
     Returns (ColumnarRSeq, max_n_unique); max_n_unique > capacity means
     some pairwise union truncated."""
-    from crdt_tpu.utils.tracing import trace_region
+    from crdt_tpu.obs.trace import region
 
-    with trace_region("rseq_columnar.converge"):
+    with region("crdt.rseq_columnar.converge"):
         work, max_nu = lub_lane(col, alive, interpret=interpret)
         return _broadcast_top(col, work, alive), max_nu
 

@@ -255,13 +255,13 @@ def gc_round(sw, adapter, neutral_inner, engine: str = "auto"):
     silently-dropped rows (see GcOverflow)."""
     from crdt_tpu.ops import joins as joins_mod
     from crdt_tpu.parallel import swarm as swarm_mod
-    from crdt_tpu.utils.tracing import trace_region
+    from crdt_tpu.obs.trace import region
 
     neutral = wrap(neutral_inner, sw.state.floor.shape[-1])
     jbc = jax.vmap(lambda x, y: join_checked(x, y, adapter))
     cap = adapter.capacity_of(neutral_inner)
 
-    with trace_region("tomb_gc.barrier"):
+    with region("crdt.tomb_gc.barrier"):
         converged = None
         hook = getattr(adapter, "columnar_converge", None)
         if engine != "generic" and hook is not None:

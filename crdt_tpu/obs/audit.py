@@ -47,6 +47,7 @@ from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
+from crdt_tpu.obs.trace import region
 from crdt_tpu.ops import digest as digops
 
 # crdt_audit_state gauge values
@@ -211,6 +212,7 @@ class PlaneDigest:
 
     # ---- frontier clamp ----
 
+    @region("crdt.audit.digest_at")
     def digest_at(self, frontier: Dict[int, int]
                   ) -> Tuple[int, int, int, int]:
         """The digest of state at-or-under ``frontier``: start from the
@@ -374,6 +376,7 @@ class AuditWatchdog:
 
     # ---- continuous evaluators ----
 
+    @region("crdt.audit.evaluate")
     def evaluate(self) -> None:
         """One watchdog tick: scrub (cadenced), frontier stall,
         convergence-lag EWMA breach, lease zombie window.  Drive once

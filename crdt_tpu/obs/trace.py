@@ -1,23 +1,37 @@
-"""Cross-node gossip tracing: trace IDs minted per gossip round, carried
-over the wire in the ``X-CRDT-Trace`` header, and correlated with device
-profiles via ``jax.profiler.TraceAnnotation`` regions of the same name.
+"""The one span facility: profiler annotations on the served path, and
+cross-node gossip trace IDs.
 
-A trace ID names ONE anti-entropy round end-to-end: the puller mints it
-(``mint_trace_id``), sends it with the /gossip request, and both sides
-record it in their event logs (crdt_tpu.obs.events) — so a two-node pull
-produces event lines on both nodes sharing one ID, greppable across the
-fleet's JSONL files.  ``span`` additionally opens a profiler annotation,
-so when a device trace is being captured (utils.tracing.trace_to) the
-host-side round and its device-side join dispatches line up by name in
-TensorBoard/xprof.
+Every span is a ``jax.profiler.TraceAnnotation`` whose name starts with
+``crdt.``: while a profiler session captures the process, the host span
+lands in the same trace, on the same clock, as the device ops it
+surrounds; with no session running it costs a microsecond or two and keeps
+nothing.  Spans are opened per request, drain, contended lock
+acquisition, round, dispatch or collection, never per op.
+
+* :func:`region` — a plain named annotation (a context manager or a
+  decorator);
+* :func:`span` — a region that also binds a gossip round's trace ID.  A
+  trace ID names ONE anti-entropy round end-to-end: the puller mints it
+  (:func:`mint_trace_id`), sends it with the /gossip request in the
+  ``X-CRDT-Trace`` header, and both sides record it in their event logs
+  (crdt_tpu.obs.events), so a two-node pull produces event lines on both
+  nodes sharing one ID;
+* :class:`SpanLock` — a lock whose contended acquisitions are spans;
+* :func:`install_gc_spans` — a span per generation-1/2 collection of
+  Python's garbage collector.
+
+The span names, what each covers, and how to capture them are listed in
+crdt_tpu/obs/README.md ("Spans on the served path").
 """
 from __future__ import annotations
 
 import contextlib
 import contextvars
+import gc
 import itertools
 import os
 import threading
+from typing import Optional
 
 TRACE_HEADER = "X-CRDT-Trace"
 
@@ -46,21 +60,90 @@ def current_trace():
 
 
 @contextlib.contextmanager
+def region(name: str, trace_id: Optional[str] = None):
+    """A profiler annotation named ``name``, or ``name#trace=<id>`` when
+    ``trace_id`` names the gossip round it serves; it mints and binds no
+    trace ID.  Yields the annotation's name.  A context manager, and a
+    decorator that opens a fresh annotation per call.  jax is imported on
+    first use, so event-log-only consumers (the crash-soak report reader)
+    never pay the import."""
+    from jax.profiler import TraceAnnotation
+
+    label = f"{name}#trace={trace_id}" if trace_id else name
+    with TraceAnnotation(label):
+        yield label
+
+
+@contextlib.contextmanager
 def span(name: str, trace_id=None):
-    """Bind ``trace_id`` (or the enclosing one) as current and open a
-    same-named profiler annotation, so the host span and its device
-    dispatches correlate by name in a captured trace.  Yields the trace
-    ID.  jax is imported lazily: event-log-only consumers (the crash-soak
-    report reader) never pay the import."""
+    """Bind ``trace_id`` (or the enclosing one, or a fresh one) as
+    current and open a same-named :func:`region`, so the host span and
+    its device dispatches correlate by name in a captured trace.  Yields
+    the trace ID."""
     tid = trace_id or current_trace() or mint_trace_id()
     token = _CURRENT.set(tid)
     try:
-        try:
-            import jax
-            annotation = jax.profiler.TraceAnnotation(name)
-        except ImportError:  # pragma: no cover - jax is a hard dep in-tree
-            annotation = contextlib.nullcontext()
-        with annotation:
+        with region(name):
             yield tid
     finally:
         _CURRENT.reset(token)
+
+
+class SpanLock:
+    """A ``threading.Lock`` whose CONTENDED acquisitions are spans: it
+    first tries to take the lock without blocking and opens ``name``
+    only when it has to wait, so the span covers exactly the wait and an
+    uncontended acquisition records nothing.  Same ``acquire`` /
+    ``release`` / ``with`` API as the lock it wraps."""
+
+    __slots__ = ("_acquire", "release", "name")
+
+    def __init__(self, name: str):
+        # the wrapped lock's bound methods: acquire() returns holding the
+        # lock by contract, so no call site here is a lock to release
+        lock = threading.Lock()
+        self._acquire = lock.acquire
+        self.release = lock.release
+        self.name = name
+
+    def acquire(self, blocking: bool = True, timeout: float = -1) -> bool:
+        if self._acquire(False):
+            return True
+        if not blocking:
+            return False
+        with region(self.name):
+            return self._acquire(True, timeout)
+
+    def __enter__(self) -> bool:
+        return self.acquire()
+
+    def __exit__(self, *exc) -> None:
+        self.release()
+
+
+GC_SPAN = "crdt.py_gc"
+# the open collection annotation of each thread (a collection starts and
+# stops on the thread that triggered it)
+_GC_OPEN = threading.local()
+
+
+def _gc_callback(phase: str, info: dict) -> None:
+    if info["generation"] == 0:
+        return  # young collections are frequent and short: no span
+    if phase == "start":
+        ann = region(GC_SPAN)
+        ann.__enter__()
+        _GC_OPEN.ann = ann
+    else:
+        ann = getattr(_GC_OPEN, "ann", None)
+        if ann is not None:
+            _GC_OPEN.ann = None
+            ann.__exit__(None, None, None)
+
+
+def install_gc_spans() -> None:
+    """Open a ``crdt.py_gc`` span over every generation-1 and -2
+    collection of this process (a ``gc.callbacks`` hook; installing it
+    again is a no-op)."""
+    if _gc_callback not in gc.callbacks:
+        gc.callbacks.append(_gc_callback)

@@ -89,21 +89,8 @@ def test_compile_cache_enable(monkeypatch, env):
 
 
 def test_peak_table_unknown_device_gets_nothing():
-    from crdt_tpu.api.node import ReplicaNode
     from crdt_tpu.obs import devtime
-    from crdt_tpu.obs.registry import MetricsRegistry
-    from crdt_tpu.utils.metrics import Metrics
 
     assert devtime.hbm_peak("TPU v5 lite") == 819e9
     assert devtime.hbm_peak("cpu") is None
     assert devtime.hbm_peak("TPU v99") is None
-    devtime._dispatch_counts.pop(("0", "merge"), None)
-    node = ReplicaNode(rid=0, capacity=64,
-                       metrics=Metrics(registry=MetricsRegistry()))
-    node.add_command({"a": "1"})
-    reg = node.metrics.registry
-    assert reg.gauge_value("join_hbm_utilization",
-                           node="0", kind="merge") is None
-    if reg.gauge_value("join_bytes_per_dispatch", node="0", kind="merge"):
-        assert reg.counter_value("join_peak_unknown",
-                                 node="0", kind="merge") == 1
