@@ -48,7 +48,8 @@ from crdt_tpu.consistency.stability import (
 from crdt_tpu.obs.audit import AuditWatchdog
 from crdt_tpu.obs.events import EventLog
 from crdt_tpu.obs.trace import (TRACE_HEADER, install_gc_spans,
-                                mint_trace_id, region, span)
+                                install_gil_probe, mint_trace_id, region,
+                                span)
 from crdt_tpu.utils.config import ClusterConfig
 from crdt_tpu.utils.metrics import Metrics
 
@@ -1608,6 +1609,10 @@ class NodeHost:
         # a collector pause on a replica's heap stalls every thread of the
         # process: each gen-1/2 collection is a crdt.py_gc span
         install_gc_spans()
+        # ... and a thread of it that waits for the interpreter shows in
+        # the crdt.probe.gil spans (one probe thread per process; it spans
+        # only while a profiler session captures the process)
+        install_gil_probe()
         # event_log: JSONL file sink path — each gossip round / barrier /
         # fault transition appends one line (the daemon's black box; the
         # crash soak points this at the checkpoint dir)

@@ -1328,6 +1328,7 @@ class ReplicaNode:
 
     # ---- internals ----
 
+    @region("crdt.accept")
     def _accept_locked(self, rows) -> List[Tuple[int, int, int, Dict[str, str]]]:
         """Filter duplicate / already-folded rows, record the survivors in
         the command map + delta indexes, and return them.  Rows are taken in
@@ -1362,6 +1363,7 @@ class ReplicaNode:
                 self.digest.observe_rows(accepted, self.clock.epoch_ms)
         return accepted
 
+    @region("crdt.pack")
     def _pack_accepted_locked(
         self, accepted: List[Tuple[int, int, int, Dict[str, str]]]
     ) -> Tuple[Optional[Dict[str, np.ndarray]], int]:
@@ -1417,6 +1419,7 @@ class ReplicaNode:
         self._merge_batch(ops, fresh)
         return fresh
 
+    @region("crdt.pack")
     def _pack_local_batch(
         self, cmds: List[Dict[str, str]], tss: List[int], seq0: int
     ) -> Tuple[Optional[Dict[str, np.ndarray]], int]:
@@ -1553,9 +1556,13 @@ class ReplicaNode:
         batch = oplog.from_host_ops(batch_cap, ops)
         with devtime.dispatch_annotation("merge"):
             merged, n_unique = oplog.merge_checked_donating(self.log, batch)
-        assert int(n_unique) <= self.log.capacity
+        # the one host sync of the merge: the lock holder waits here for
+        # its own merge and any queued ahead of it on the device
+        with region("crdt.wait.device"):
+            n_unique = int(n_unique)
+        assert n_unique <= self.log.capacity
         self.log = merged
-        self._log_rows = int(n_unique)  # already synced by the assert
+        self._log_rows = n_unique
         self.metrics.inc("ops_ingested", fresh)
 
     def _grow(self) -> None:

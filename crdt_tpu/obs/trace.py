@@ -18,7 +18,10 @@ acquisition, round, dispatch or collection, never per op.
   nodes sharing one ID;
 * :class:`SpanLock` — a lock whose contended acquisitions are spans;
 * :func:`install_gc_spans` — a span per generation-1/2 collection of
-  Python's garbage collector.
+  Python's garbage collector;
+* :func:`install_gil_probe` — a daemon thread whose fixed 1 ms sleeps are
+  spans while a profiler session runs, so a span's length beyond 1 ms is
+  how long a runnable Python thread waited to run again.
 
 The span names, what each covers, and how to capture them are listed in
 crdt_tpu/obs/README.md ("Spans on the served path").
@@ -31,6 +34,7 @@ import gc
 import itertools
 import os
 import threading
+import time
 from typing import Optional
 
 TRACE_HEADER = "X-CRDT-Trace"
@@ -147,3 +151,54 @@ def install_gc_spans() -> None:
     again is a no-op)."""
     if _gc_callback not in gc.callbacks:
         gc.callbacks.append(_gc_callback)
+
+
+PROBE_SPAN = "crdt.probe.gil"
+#: while a profiler session captures the process, the probe wakes once per
+#: PROBE_PERIOD_S and sleeps a fixed PROBE_SLEEP_S inside its span; with no
+#: session it only looks for one every PROBE_IDLE_S and opens nothing.  All
+#: three are constants, not configuration
+PROBE_PERIOD_S = 0.010
+PROBE_SLEEP_S = 0.001
+PROBE_IDLE_S = 1.0
+_PROBE_LOCK = threading.Lock()
+_PROBE: Optional[threading.Thread] = None
+
+
+def _probe_once() -> float:
+    """One turn of the probe: a span around the fixed sleep while a
+    profiler session runs, nothing otherwise.  Returns how long to sleep
+    before the next turn."""
+    # a bare annotation: nothing but the sleep and the GIL re-take inside
+    from jax.profiler import TraceAnnotation
+
+    if not TraceAnnotation.is_enabled():
+        return PROBE_IDLE_S
+    with TraceAnnotation(PROBE_SPAN):
+        time.sleep(PROBE_SLEEP_S)
+    return PROBE_PERIOD_S - PROBE_SLEEP_S
+
+
+def _probe_loop() -> None:
+    while True:
+        time.sleep(_probe_once())
+
+
+def install_gil_probe() -> threading.Thread:
+    """Start the interpreter-wait probe: one daemon thread that, while a
+    profiler session captures this process, every 10 ms opens a
+    ``crdt.probe.gil`` span, sleeps a fixed 1 ms (which releases the GIL)
+    and closes it.  Closing the span needs the GIL again, so its length
+    beyond 1 ms is how long a runnable Python thread of this process
+    waited to run: contention for the GIL, or a stall of the whole host
+    (a process that runs none of this code, sleeping beside it, tells the
+    two apart).  With no session it wakes once a second to look for one,
+    and spans nothing.  It draws no random numbers.  Installing it again
+    returns the running thread."""
+    global _PROBE
+    with _PROBE_LOCK:
+        if _PROBE is None:
+            _PROBE = threading.Thread(target=_probe_loop,
+                                      name="crdt-gil-probe", daemon=True)
+            _PROBE.start()
+        return _PROBE

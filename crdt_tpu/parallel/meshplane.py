@@ -48,6 +48,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from crdt_tpu.models import oplog
+from crdt_tpu.obs.trace import region
 from crdt_tpu.ops import union_engine
 from crdt_tpu.parallel.mesh import make_mesh
 from crdt_tpu.utils.metrics import Metrics
@@ -235,7 +236,8 @@ class MeshPlane:
             with self.metrics.timer("merge"):
                 lanes, n_unique, dig_sum = step(logs, cols, digs)
                 # ONE host sync for all lanes' counts AND digest sums
-                n_host, dig_host = jax.device_get((n_unique, dig_sum))
+                with region("crdt.wait.device"):
+                    n_host, dig_host = jax.device_get((n_unique, dig_sum))
         except Exception as exc:
             # engine failure: land every lane with its own inline host
             # dispatch so no lane is left with indexes ahead of its log,
